@@ -9,6 +9,8 @@ import sys
 import time
 import traceback
 
+from repro.compile_cache import enable_compile_cache
+
 
 SECTIONS = [
     ("kernels", "benchmarks.kernels_bench"),
@@ -26,6 +28,7 @@ SECTIONS = [
 
 
 def main() -> None:
+    enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--only", default=None)
     p.add_argument("--fast", action="store_true")

@@ -20,6 +20,7 @@ import argparse
 import functools
 
 from repro.agents.dqn import DQNBuilder, DQNConfig
+from repro.compile_cache import enable_compile_cache
 from repro.envs import Catch
 from repro.experiments import ExperimentConfig, run_distributed_experiment
 
@@ -33,6 +34,7 @@ def make_env(seed: int):
 
 
 def main():
+    enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--actors", type=int, default=4)
     p.add_argument("--actor-steps", type=int, default=6000)

@@ -15,6 +15,7 @@ import numpy as np
 from repro.adders import NStepTransitionAdder
 from repro.agents import bc as bc_lib
 from repro.agents import dqn as dqn_lib
+from repro.compile_cache import enable_compile_cache
 from repro.core import EnvironmentLoop, FeedForwardActor, VariableClient, make_environment_spec
 from repro.envs import Catch
 from repro.experiments import ExperimentConfig, run_offline_experiment
@@ -46,6 +47,7 @@ def evaluate(learner, policy, episodes=25):
 
 
 def main():
+    enable_compile_cache()
     items = collect()
     print(f"dataset: {len(items)} transitions from an expert policy")
 

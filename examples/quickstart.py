@@ -11,11 +11,13 @@ and the identical builder runs as a Launchpad-lite program instead
 import numpy as np
 
 from repro.agents.dqn import DQNBuilder, DQNConfig
+from repro.compile_cache import enable_compile_cache
 from repro.envs import Catch
 from repro.experiments import ExperimentConfig, run_experiment
 
 
 def main():
+    enable_compile_cache()
     config = ExperimentConfig(
         builder_factory=lambda spec: DQNBuilder(
             spec, DQNConfig(min_replay_size=50, samples_per_insert=0.0,

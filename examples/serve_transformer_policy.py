@@ -12,12 +12,14 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import ARCHS, reduced
 from repro.launch.steps import make_batched_prefill_step, make_serve_step
 from repro.models import transformer
 
 
 def main():
+    enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--arch", default="qwen3-1.7b", choices=sorted(ARCHS))
     p.add_argument("--batch", type=int, default=4)

@@ -7,6 +7,7 @@ through the experiments API.
 import numpy as np
 
 from repro.agents.continuous import ContinuousBuilder, ContinuousConfig
+from repro.compile_cache import enable_compile_cache
 from repro.envs import PendulumSwingup
 from repro.experiments import ExperimentConfig, run_experiment
 
@@ -14,6 +15,7 @@ EPISODE_LEN = 150
 
 
 def main():
+    enable_compile_cache()
     cfg = ContinuousConfig(algo="d4pg", hidden=64, batch_size=64,
                            min_replay_size=300, samples_per_insert=0.0,
                            n_step=3, sigma=0.3, vmin=0.0,

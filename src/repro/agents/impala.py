@@ -2,7 +2,8 @@
 
 Data flows through a FIFO queue (non-overlapping sequences, processed in
 order) exactly as the paper describes; the V-trace recursion runs through the
-Pallas kernel (interpret mode off-TPU) with the pure-jnp ref as fallback.
+pure-jnp ``kernels/ref.py`` scan (the Pallas ``vtrace_kernel`` does not
+compile for the TPU).
 The behaviour logits are stored by the actor as extras so the learner can
 form the importance ratios.
 """

@@ -318,6 +318,12 @@ def _child_main(node_name, payload, control_pipe, error_queue, restarts=0):
     # SIGUSR1 dumps every thread's stack to stderr — the only way to see
     # where a live worker is stuck from outside (hangs, chaos debugging).
     faulthandler.register(signal.SIGUSR1, all_threads=True)
+    # One process per chip: the parent keeps the services and may hold the
+    # accelerator, so worker children run JAX on the CPU.  Pinned before the
+    # payload is unpickled, so nothing in it can start a backend first.
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    if "jax" in sys.modules:
+        sys.modules["jax"].config.update("jax_platforms", "cpu")
     os.environ[RESTARTS_ENV] = str(restarts)
     flags = {"stop": False, "user": False}
     try:
@@ -346,7 +352,13 @@ class MultiprocessLauncher(LauncherBase):
     Service nodes are resolved in the parent and exported over courier;
     pickling a worker's arguments rewrites its ``Handle`` edges into
     ``RemoteHandle`` stubs bound to those servers (``Handle.__reduce__``),
-    so node code is byte-identical across backends.  Child failures flow
+    so node code is byte-identical across backends.
+
+    Placement on the device: the parent alone may use the accelerator (the
+    learner, replay and any inference server live there).  Every worker
+    child is pinned to ``JAX_PLATFORMS=cpu`` before its node is built, so
+    local-inference actors and evaluators act on the CPU, as in Acme, and
+    never contend with the parent for the chip.  Child failures flow
     back through an error queue into the parent's fail-fast stop, with the
     same ``WorkerErrors`` aggregation and shutdown-noise rules as
     ``LocalLauncher``.

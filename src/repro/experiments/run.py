@@ -227,11 +227,18 @@ def run_distributed_experiment(config: ExperimentConfig, num_actors: int,
                                max_actor_steps: Optional[int] = None,
                                timeout_s: float = 300.0,
                                with_evaluator: bool = False,
-                               poll_s: float = 0.2) -> ExperimentResult:
+                               poll_s: float = 0.2,
+                               max_learner_steps: Optional[int] = None
+                               ) -> ExperimentResult:
     """Distributed run: the SAME builder, unchanged, on the Launchpad-lite
     graph (Fig 4) — N actor nodes + learner + rate-limited replay.  The
     execution backend comes from ``config.launcher`` (``"local"`` threads or
-    ``"multiprocess"`` OS processes with courier RPC edges)."""
+    ``"multiprocess"`` OS processes with courier RPC edges).
+
+    The run ends at the first of: ``max_actor_steps`` actor steps,
+    ``max_learner_steps`` SGD steps (the learner stops stepping there), or
+    ``timeout_s``.  A learner-step target cannot race the first compile of
+    a large learner step the way an actor-step target can."""
     if num_actors < 1:
         raise ValueError(f"num_actors must be >= 1, got {num_actors}")
     spec = make_environment_spec(config.environment_factory(config.seed))
@@ -259,6 +266,7 @@ def run_distributed_experiment(config: ExperimentConfig, num_actors: int,
 
     dist = make_distributed_agent(builder, config.environment_factory,
                                   num_actors=num_actors, seed=config.seed,
+                                  max_learner_steps=max_learner_steps,
                                   with_evaluator=with_evaluator,
                                   num_replay_shards=config.num_replay_shards,
                                   prefetch_size=config.prefetch_size,
@@ -306,6 +314,9 @@ def run_distributed_experiment(config: ExperimentConfig, num_actors: int,
         while time.time() - t0 < timeout_s:
             counts = dist.counter.get_counts()
             if target is not None and counts.get("actor_steps", 0) >= target:
+                break
+            if max_learner_steps is not None and \
+                    int(dist.learner.state.steps) >= max_learner_steps:
                 break
             if checkpointer and config.checkpoint_every:
                 learner_steps = int(dist.learner.state.steps)

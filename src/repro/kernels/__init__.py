@@ -2,7 +2,7 @@
 
 Each kernel ships three artifacts (per the repo convention):
   <name>.py  — pl.pallas_call + BlockSpec implementation (TPU target)
-  ops.py     — jitted public wrappers (interpret=True off-TPU)
+  ops.py     — jitted public wrappers (interpret=True only on request)
   ref.py     — pure-jnp oracles used by the allclose test sweeps
 """
 from repro.kernels import ops, ref  # noqa: F401

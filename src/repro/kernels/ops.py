@@ -1,8 +1,9 @@
 """Jitted public wrappers around the Pallas kernels.
 
-``interpret`` defaults to True when no TPU is present (this container), so
-the same call sites run the kernel bodies on CPU for correctness and compile
-the real Mosaic kernels on TPU.
+Every wrapper compiles the Mosaic kernel for the TPU unless the caller
+passes ``interpret=True``, which runs the kernel body through the Pallas
+interpreter on any backend (how the CPU tests check them against
+``ref.py``).  Nothing here picks interpret mode by itself.
 """
 from __future__ import annotations
 
@@ -16,37 +17,28 @@ from repro.kernels.ssd_scan import ssd_scan as _ssd
 from repro.kernels.vtrace_kernel import vtrace as _vtrace
 
 
-@functools.cache
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 @functools.partial(jax.jit, static_argnames=("causal", "window", "block_q",
                                              "block_k", "interpret"))
 def flash_attention(q, k, v, *, causal=True, window=None, block_q=128,
-                    block_k=128, interpret=None):
-    interpret = _default_interpret() if interpret is None else interpret
+                    block_k=128, interpret=False):
     return _flash(q, k, v, causal=causal, window=window, block_q=block_q,
                   block_k=block_k, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
-def decode_attention(q, k, v, lengths, *, block_k=512, interpret=None):
-    interpret = _default_interpret() if interpret is None else interpret
+def decode_attention(q, k, v, lengths, *, block_k=512, interpret=False):
     return _decode(q, k, v, lengths, block_k=block_k, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def ssd_scan(x, dt, A, B, C, *, chunk=256, interpret=None):
-    interpret = _default_interpret() if interpret is None else interpret
+def ssd_scan(x, dt, A, B, C, *, chunk=256, interpret=False):
     return _ssd(x, dt, A, B, C, chunk=chunk, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("clip_rho", "clip_c", "block_b",
                                              "interpret"))
 def vtrace(values, next_values, rewards, discounts, rhos, *, clip_rho=1.0,
-           clip_c=1.0, block_b=128, interpret=None):
-    interpret = _default_interpret() if interpret is None else interpret
+           clip_c=1.0, block_b=128, interpret=False):
     return _vtrace(values, next_values, rewards, discounts, rhos,
                    clip_rho=clip_rho, clip_c=clip_c, block_b=block_b,
                    interpret=interpret)

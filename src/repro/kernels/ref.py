@@ -26,14 +26,18 @@ def flash_attention_ref(q, k, v, *, causal=True, window=None, scale=None):
 
 
 def decode_attention_ref(q, k, v, lengths):
-    """q: (b, h, d); k, v: (b, s, h, d); lengths: (b,) valid prefix lengths."""
-    scale = q.shape[-1] ** -0.5
-    scores = jnp.einsum("bhd,bshd->bhs", q.astype(jnp.float32),
-                        k.astype(jnp.float32)) * scale
+    """q: (b, h, d); k, v: (b, s, kv, d) with ``h % kv == 0`` (query head i
+    reads KV head ``i // (h // kv)``); lengths: (b,) valid prefix lengths."""
+    b, h, d = q.shape
+    kv = k.shape[2]
+    qg = q.reshape(b, kv, h // kv, d).astype(jnp.float32)
+    scores = jnp.einsum("bngd,bsnd->bngs", qg, k.astype(jnp.float32)) \
+        * d ** -0.5
     valid = jnp.arange(k.shape[1])[None, :] < lengths[:, None]
-    scores = jnp.where(valid[:, None, :], scores, NEG_INF)
+    scores = jnp.where(valid[:, None, None, :], scores, NEG_INF)
     p = jax.nn.softmax(scores, axis=-1)
-    return jnp.einsum("bhs,bshd->bhd", p, v.astype(jnp.float32)).astype(q.dtype)
+    out = jnp.einsum("bngs,bsnd->bngd", p, v.astype(jnp.float32))
+    return out.reshape(b, h, d).astype(q.dtype)
 
 
 def ssd_scan_ref(x, dt, A, B, C, chunk):

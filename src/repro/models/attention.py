@@ -147,18 +147,18 @@ def init_kv_cache(cfg: ArchConfig, batch: int, max_len: int, dtype=jnp.bfloat16)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
-def _decode_backend(backend: str, length: int) -> str:
+def resolve_decode_backend(backend: str) -> str:
     """Resolve ``"auto"`` to a concrete decode backend.
 
-    The pallas flash-decoding kernel requires the cache length to divide its
-    k-block, and interpret mode (how pallas runs off-TPU) is far slower than
-    plain jnp — so ``auto`` picks the kernel only on a real TPU and falls
-    back to the pure-jnp ``kernels/ref.py`` oracle everywhere else.
+    ``auto`` is the pallas flash-decoding kernel on a TPU and the pure-jnp
+    ``kernels/ref.py`` oracle on every other backend (the kernel compiles
+    only for the TPU).  On a TPU a cache the kernel cannot block raises
+    from ``kernels.decode_attention.kv_block``; nothing falls back.
     """
     if backend == "auto":
-        backend = "kernel" if jax.default_backend() == "tpu" else "ref"
-    if backend == "kernel" and length % min(512, length) != 0:
-        backend = "ref"
+        return "kernel" if jax.default_backend() == "tpu" else "ref"
+    if backend not in ("jnp", "kernel", "ref"):
+        raise ValueError(f"unknown decode backend {backend!r}")
     return backend
 
 
@@ -173,9 +173,9 @@ def decode_attention(params, cfg: ArchConfig, x, cache, pos, *,
 
     ``backend`` selects the score/softmax path once the cache is updated:
     ``"jnp"`` (grouped-GQA einsum), ``"kernel"`` (the pallas flash-decoding
-    kernel — MHA layout, per-row valid prefix lengths), ``"ref"`` (the
-    pure-jnp ``kernels/ref.py`` oracle, the CPU fallback), or ``"auto"``
-    (kernel on TPU when the cache length divides the k-block, ref elsewhere).
+    kernel — per-row valid prefix lengths, TPU only), ``"ref"`` (the
+    pure-jnp ``kernels/ref.py`` oracle), or ``"auto"`` (kernel on TPU, ref
+    elsewhere — see ``resolve_decode_backend``).
     """
     pos = jnp.asarray(pos, jnp.int32)
     vector_pos = pos.ndim == 1
@@ -218,30 +218,27 @@ def decode_attention(params, cfg: ArchConfig, x, cache, pos, *,
             cache["v"], v_new.astype(cache["v"].dtype), slot, 1)
     new_cache = {"k": k, "v": v}
 
-    backend = _decode_backend(backend, length)
+    backend = resolve_decode_backend(backend)
     if backend in ("kernel", "ref"):
         # Both kernels mask a VALID PREFIX per row.  That is exactly the
         # occupancy of our caches: a linear cache holds slots [0, pos] and a
         # full ring holds all L slots — min(pos+1, L) either way.  Ring
         # wraparound scrambles chronological order, but softmax attention is
         # permutation-invariant over the key set and K is stored post-RoPE,
-        # so prefix masking stays correct after wrap.
+        # so prefix masking stays correct after wrap.  Both read the cache
+        # at its KV-head width (GQA grouped, no repeat).
         lengths = jnp.broadcast_to(jnp.minimum(pos + 1, length),
                                    (q.shape[0],)).astype(jnp.int32)
-        kf = _repeat_kv(k.astype(q.dtype), cfg.q_per_kv)
-        vf = _repeat_kv(v.astype(q.dtype), cfg.q_per_kv)
+        kq, vq = k.astype(q.dtype), v.astype(q.dtype)
         if backend == "kernel":
             from repro.kernels import ops
-            out_h = ops.decode_attention(q[:, 0], kf, vf, lengths,
-                                         block_k=min(512, length))
+            out_h = ops.decode_attention(q[:, 0], kq, vq, lengths)
         else:
             from repro.kernels import ref as kernels_ref
-            out_h = kernels_ref.decode_attention_ref(q[:, 0], kf, vf, lengths)
+            out_h = kernels_ref.decode_attention_ref(q[:, 0], kq, vq, lengths)
         out = jnp.einsum("bhk,hkd->bd", out_h.astype(q.dtype),
                          params["wo"])[:, None]
         return out, new_cache
-    if backend != "jnp":
-        raise ValueError(f"unknown decode backend {backend!r}")
 
     slots = jnp.arange(length)
     pos_col = pos[:, None] if vector_pos else pos

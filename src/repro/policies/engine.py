@@ -24,13 +24,14 @@ queries.
 """
 from __future__ import annotations
 
-from typing import Dict, List, Sequence
+from typing import Any, Dict, List, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core.actors import STEP_MOD
+from repro.models.attention import resolve_decode_backend
 from repro.models.config import ArchConfig
 from repro.policies import network
 from repro.policies.cache import KVCachePool
@@ -58,6 +59,9 @@ class PolicyEngine:
         self.num_actions = num_actions
         self.epsilon = float(epsilon)
         self.pool = KVCachePool(arch, num_slots, timeout_s=slot_timeout_s)
+        # resolved once, in the process that runs the engine, and reported
+        # in stats() so a run says which decode path it took
+        self.decode_backend = backend = resolve_decode_backend(backend)
         self._key = jax.random.key(rng_seed)
         self._step = 0
         self._last_params = None
@@ -210,11 +214,12 @@ class PolicyEngine:
     def release_client(self, client_id):
         self.pool.release_prefix(client_id)
 
-    def stats(self) -> Dict[str, int]:
+    def stats(self) -> Dict[str, Any]:
         s = dict(self._stats)
         s.update({f"pool_{k}": v for k, v in self.pool.stats.items()})
         s["pool_held_slots"] = self.pool.held()
         s["pool_utilization"] = self.pool.held() / max(self.pool.num_slots, 1)
         s["prefill_decode_ratio"] = (s["prefill_rows"]
                                      / max(s["decode_rows"], 1))
+        s["decode_backend"] = self.decode_backend
         return s

@@ -91,6 +91,17 @@ class Spinner:
         self._stop.set()
 
 
+class PlatformReporter:
+    """Worker: reports the JAX platforms its process is pinned to."""
+
+    def __init__(self, sink):
+        self.sink = sink
+
+    def run(self):
+        import jax
+        self.sink.put(jax.config.jax_platforms)
+
+
 class Stubborn:
     """Worker that ignores stop requests (for join-timeout reporting)."""
 
@@ -271,6 +282,20 @@ def test_handle_pickling_roundtrip(backend):
     finally:
         launcher.stop()
         launcher.join(timeout=JOIN_S)
+
+
+def test_multiprocess_workers_are_pinned_to_cpu(monkeypatch):
+    """One process per chip: a worker child runs JAX on the CPU even when
+    the parent's environment names no platform (on a TPU host the parent
+    holds the chip)."""
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    prog = Program()
+    sink = prog.add_node("sink", Sink, role="service",
+                         interface=("put", "items"))
+    prog.add_node("reporter", PlatformReporter, sink, role="worker")
+    launcher = get_launcher("multiprocess")(prog).launch()
+    launcher.join(timeout=JOIN_S)
+    assert prog.resolve("sink").items() == ["cpu"]
 
 
 # ----------------------------------------- learner-replica node conformance

@@ -82,6 +82,48 @@ def test_decode_attention_policy_serve_shapes(b, h, s, d, dtype):
                                atol=_tol(dtype), rtol=_tol(dtype))
 
 
+@pytest.mark.parametrize("b,h,kv,s,d", [
+    (8, 4, 2, 8, 32),       # the default policy: 4 heads over 2 KV heads
+    (4, 8, 1, 16, 16),      # multi-query
+    (2, 32, 4, 1024, 128),  # Yi-6B head layout, two kv blocks
+])
+def test_decode_attention_gqa_matches_repeated_kv(b, h, kv, s, d):
+    """Grouped query heads read their KV head in place: the same result as
+    MHA over a cache with every KV head repeated ``h // kv`` times."""
+    q = jnp.asarray(RNG.randn(b, h, d), jnp.float32)
+    k = jnp.asarray(RNG.randn(b, s, kv, d), jnp.float32)
+    v = jnp.asarray(RNG.randn(b, s, kv, d), jnp.float32)
+    lengths = jnp.asarray(RNG.randint(1, s + 1, b), jnp.int32)
+    out = ops.decode_attention(q, k, v, lengths, interpret=True)
+    expected = ref.decode_attention_ref(q, jnp.repeat(k, h // kv, axis=2),
+                                        jnp.repeat(v, h // kv, axis=2),
+                                        lengths)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(expected),
+                               atol=1e-4, rtol=1e-4)
+    np.testing.assert_allclose(
+        np.asarray(ref.decode_attention_ref(q, k, v, lengths)),
+        np.asarray(expected), atol=1e-5, rtol=1e-5)
+
+
+def test_decode_attention_refuses_unblockable_cache():
+    """A cache longer than one k-block that the block does not divide is an
+    error naming its length — never a silent switch to another path."""
+    q = jnp.zeros((1, 2, 16), jnp.float32)
+    k = jnp.zeros((1, 600, 2, 16), jnp.float32)
+    with pytest.raises(ValueError, match="length 600"):
+        ops.decode_attention(q, k, k, jnp.ones((1,), jnp.int32),
+                             interpret=True)
+
+
+def test_auto_decode_backend_is_ref_off_tpu():
+    from repro.models.attention import resolve_decode_backend
+    assert jax.default_backend() != "tpu"
+    assert resolve_decode_backend("auto") == "ref"
+    assert resolve_decode_backend("kernel") == "kernel"
+    with pytest.raises(ValueError, match="unknown decode backend"):
+        resolve_decode_backend("pallas")
+
+
 @pytest.mark.parametrize("b,s,h,p,n,chunk", [
     (1, 256, 2, 32, 16, 64),
     (2, 512, 4, 64, 32, 128),
