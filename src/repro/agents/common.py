@@ -9,6 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.interfaces import Learner
+from repro.telemetry import registry as _telemetry
 
 
 class LearnerState(NamedTuple):
@@ -34,6 +35,7 @@ class JaxLearner(Learner):
         self._iterator = iterator
         self._priority_cb = priority_update_cb
         self._walltime = 0.0
+        self._num_steps = 0   # this object's steps: the spans' ids
         self._metrics: Dict[str, float] = {}
 
     @property
@@ -49,18 +51,27 @@ class JaxLearner(Learner):
         return self._walltime
 
     def step(self) -> Dict[str, float]:
-        sample = next(self._iterator)
-        t0 = time.time()
-        self._state, metrics, priorities = self._update(self._state, sample)
-        jax.block_until_ready(priorities if priorities is not None
-                              else metrics)
-        self._walltime += time.time() - t0
+        # four spans that split the step without overlap, so a device idle
+        # gap falls in exactly one phase: input, update, priorities, metrics
+        step = self._num_steps
+        self._num_steps += 1
+        with _telemetry.span("learner/input", step=step):
+            sample = next(self._iterator)
+        with _telemetry.span("learner/update", step=step):
+            t0 = time.time()
+            self._state, metrics, priorities = self._update(self._state,
+                                                            sample)
+            jax.block_until_ready(priorities if priorities is not None
+                                  else metrics)
+            self._walltime += time.time() - t0
         if self._priority_cb is not None and priorities is not None:
-            self._priority_cb(np.asarray(sample.info.keys),
-                              np.asarray(priorities))
+            with _telemetry.span("learner/priorities", step=step):
+                self._priority_cb(np.asarray(sample.info.keys),
+                                  np.asarray(priorities))
         # ONE host transfer for all metrics + the step counter (a float(v)
         # per entry is a separate blocking device sync each).
-        host_metrics, steps = jax.device_get((metrics, self._state.steps))
+        with _telemetry.span("learner/metrics", step=step):
+            host_metrics, steps = jax.device_get((metrics, self._state.steps))
         self._metrics = {k: float(v) for k, v in host_metrics.items()}
         self._metrics["learner_steps"] = float(steps)
         self._metrics["learner_walltime"] = self._walltime

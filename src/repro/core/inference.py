@@ -104,7 +104,6 @@ class _BatchingServer:
         # Null (falsy) metrics when telemetry is off — the hot paths below
         # guard their clock reads on truthiness.
         self._m_queue_wait = _telemetry.histogram("inference/queue_wait_ms")
-        self._m_batch_rows = _telemetry.histogram("inference/batch_rows")
         self._m_batch_occupancy = _telemetry.histogram(
             "inference/batch_occupancy")
         _telemetry.probe("inference/server", self.stats)
@@ -183,21 +182,22 @@ class _BatchingServer:
                 self._cond.wait(remaining)
 
     def _run_batch(self, batch: List[_Request]):
+        rows = sum(r.rows for r in batch)
         if self._m_queue_wait:
             now = time.monotonic()
-            rows = 0
             for request in batch:
-                rows += request.rows
                 if request.t0 is not None:
                     self._m_queue_wait.observe((now - request.t0) * 1000.0)
-            self._m_batch_rows.observe(rows)
             self._m_batch_occupancy.observe(rows / self._max_batch)
         try:
-            results, extra = self._execute(batch)
+            # only this thread counts batches, so the read needs no lock
+            with _telemetry.span("inference/batch",
+                                 batch=self._stats["batches"], rows=rows):
+                results, extra = self._execute(batch)
             with self._cond:
                 self._stats["batches"] += 1
                 self._stats["requests"] += len(batch)
-                self._stats["rows"] += sum(r.rows for r in batch)
+                self._stats["rows"] += rows
                 for k, v in extra.items():
                     self._stats[k] = self._stats.get(k, 0) + v
             for request, result in zip(batch, results):

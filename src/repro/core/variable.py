@@ -13,6 +13,7 @@ import threading
 from typing import Any, List, Optional, Sequence
 
 from repro.core.interfaces import VariableSource
+from repro.telemetry import registry as _telemetry
 
 
 class VariableClient:
@@ -51,7 +52,8 @@ class VariableClient:
             self.update_and_wait()
 
     def update_and_wait(self):
-        self._params = self._source.get_variables(self._names)
+        with _telemetry.span("variables/fetch"):
+            self._params = self._source.get_variables(self._names)
         self._fresh = False
 
     # -- exact resume (repro.resilience) -------------------------------

@@ -20,7 +20,10 @@ Weight refresh detection is object identity on ``params`` (a
 ``VariableClient`` only rebinds ``.params`` when it actually fetched new
 weights): a refresh bumps the pool generation, so every live slot
 re-prefills before its next decode rather than mixing stale K/V into fresh
-queries.
+queries.  The same check sizes, once per weights object, the parameter
+bytes each jitted call copies to the device, counted (telemetry on) in
+``engine/param_bytes_to_device``; ``engine/prefill`` and ``engine/decode``
+span each path's whole call.
 """
 from __future__ import annotations
 
@@ -65,6 +68,7 @@ class PolicyEngine:
         self._key = jax.random.key(rng_seed)
         self._step = 0
         self._last_params = None
+        self._param_host_bytes = 0
         self._stats = {"prefill_rows": 0, "decode_rows": 0,
                        "prefill_batches": 0, "decode_batches": 0,
                        "cache_invalidations": 0, "stale_reprefills": 0}
@@ -114,6 +118,11 @@ class PolicyEngine:
                 self.pool.invalidate_all()
                 self._stats["cache_invalidations"] += 1
             self._last_params = params
+            # what each jitted call copies to the device: leaves that are
+            # not device arrays yet (host numpy from a VariableClient)
+            self._param_host_bytes = sum(
+                leaf.nbytes for leaf in jax.tree.leaves(params)
+                if not isinstance(leaf, jax.Array))
 
         windows = np.asarray(windows, np.float32)
         positions = np.asarray(positions, np.int64)
@@ -140,12 +149,17 @@ class PolicyEngine:
                 prefill_rows.append(i)
             slots.append(slot)
 
+        param_bytes = _telemetry.counter("engine/param_bytes_to_device")
         if prefill_rows:
-            self._run_prefill(params, prefill_rows, slots, windows,
-                              positions, actions)
+            with _telemetry.span("engine/prefill", rows=len(prefill_rows)):
+                param_bytes.inc(self._param_host_bytes)
+                self._run_prefill(params, prefill_rows, slots, windows,
+                                  positions, actions)
         if decode_rows:
-            self._run_decode(params, decode_rows, slots, windows,
-                             positions, actions)
+            with _telemetry.span("engine/decode", rows=len(decode_rows)):
+                param_bytes.inc(self._param_host_bytes)
+                self._run_decode(params, decode_rows, slots, windows,
+                                 positions, actions)
         return actions
 
     def _pad(self, indices: List[int], bucket: int) -> np.ndarray:

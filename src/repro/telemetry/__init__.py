@@ -38,8 +38,8 @@ from repro.telemetry.registry import (
     probe,
     quantile,
     snapshot,
+    span,
     strip_reservoirs,
-    timer,
     unconfigure,
 )
 from repro.telemetry.hub import (
@@ -76,7 +76,7 @@ __all__ = [
     "probe",
     "quantile",
     "snapshot",
+    "span",
     "strip_reservoirs",
-    "timer",
     "unconfigure",
 ]

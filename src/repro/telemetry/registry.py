@@ -29,6 +29,11 @@ Probes cover state that has no event to hook: ``probe(prefix, fn)``
 registers a callable returning ``{suffix: value}`` that is evaluated at
 ``snapshot()`` time and exported as gauges named ``prefix/suffix`` —
 replay occupancy, cache-slot utilization, averaging rounds.
+
+Spans cover a block of work: ``with span("learner/update"):`` observes the
+block's milliseconds into the histogram ``learner/update_ms`` and opens the
+profiler annotation ``repro/learner/update``, which a running profiler
+trace records on the clock its device operations use.
 """
 from __future__ import annotations
 
@@ -193,22 +198,30 @@ class Histogram:
         return summary
 
 
-class _TimerContext:
-    __slots__ = ("_histogram", "_t0")
+class _Span:
+    """An enabled ``span``: the block's duration in ms into a histogram,
+    and a profiler annotation on the profiler's own clock (which the device
+    trace shares), so the block lines up with device operations whenever a
+    trace is running."""
 
-    def __init__(self, histogram: Histogram):
+    __slots__ = ("_histogram", "_annotation", "_t0")
+
+    def __init__(self, histogram: Histogram, annotation):
         self._histogram = histogram
+        self._annotation = annotation
 
     def __enter__(self):
+        self._annotation.__enter__()
         self._t0 = time.monotonic()
         return self
 
     def __exit__(self, *exc):
         self._histogram.observe((time.monotonic() - self._t0) * 1000.0)
+        self._annotation.__exit__(*exc)
         return False
 
 
-class _NullTimer:
+class _NullSpan:
     __slots__ = ()
 
     def __enter__(self):
@@ -218,13 +231,12 @@ class _NullTimer:
         return False
 
 
-_NULL_TIMER = _NullTimer()
+_NULL_SPAN = _NullSpan()
 
 
-def timer(histogram):
-    """``with timer(hist):`` — observe the block's duration in ms; a null
-    (falsy) histogram yields a no-op context that never reads the clock."""
-    return _TimerContext(histogram) if histogram else _NULL_TIMER
+def _annotation(name: str, ids: Mapping[str, Any]):
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation(f"repro/{name}", **ids)
 
 
 class MetricRegistry:
@@ -280,6 +292,16 @@ class MetricRegistry:
                 key = f"{prefix}#{n}"
                 n += 1
             self._probes[key] = fn
+
+    def span(self, name: str, **ids):
+        """``with reg.span("learner/update", step=n):`` — observe the block's
+        duration in ms into the histogram ``<name>_ms`` (exact count and
+        sum) and open the profiler annotation ``repro/<name>`` carrying
+        ``ids`` as its metadata.  Disabled, it returns one shared no-op
+        context that reads no clock and imports nothing."""
+        if not self.enabled:
+            return _NULL_SPAN
+        return _Span(self.histogram(f"{name}_ms"), _annotation(name, ids))
 
     # ------------------------------------------------------------- snapshot
     def snapshot(self) -> Dict[str, Dict[str, Any]]:
@@ -443,6 +465,15 @@ def histogram(name: str, max_samples: int = DEFAULT_RESERVOIR) -> Histogram:
 
 def probe(prefix: str, fn: Callable[[], Mapping[str, float]]):
     return _GLOBAL_REGISTRY.probe(prefix, fn)
+
+
+def span(name: str, **ids):
+    """A span of the process registry, looked up at the call: components
+    built before ``configure`` still record once telemetry is on."""
+    registry = _GLOBAL_REGISTRY
+    if not registry.enabled:   # checked here too: half the disabled cost
+        return _NULL_SPAN
+    return registry.span(name, **ids)
 
 
 def snapshot() -> Dict[str, Dict[str, Any]]:

@@ -651,6 +651,22 @@ def test_variable_client_period_still_honoured():
     assert source.fetches == 4
 
 
+def test_variable_fetch_span_counts_fetches_not_updates():
+    from repro.core import VariableClient
+    from repro.telemetry import registry
+    reg = registry.configure(enabled=True)
+    try:
+        source = _CountingSource()
+        client = VariableClient(source, update_period=5)
+        for _ in range(10):
+            client.update()
+        client.update(wait=True)
+        assert source.fetches == 4
+        assert reg.histogram("variables/fetch_ms").count == 4
+    finally:
+        registry.unconfigure()
+
+
 # --------------------------------------------- multiprocess learning smoke
 @pytest.mark.slow
 def test_multiprocess_dqn_on_catch_learning_smoke():

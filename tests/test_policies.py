@@ -234,6 +234,87 @@ def test_engine_weight_refresh_invalidates_cache():
     assert act == expected
 
 
+@pytest.fixture
+def telemetry_on():
+    from repro.telemetry import registry
+    yield registry.configure(enabled=True)
+    registry.unconfigure()
+
+
+def test_engine_counts_parameter_bytes_sent_to_the_device(telemetry_on):
+    """Every prefill or decode call with host-numpy weights copies all of
+    them to the device; with device arrays it copies none."""
+    builder = _builder()
+    obs_shape = builder.spec.observations.shape
+    engine = PolicyEngine(builder.arch, obs_shape, builder.num_actions,
+                          num_slots=4, epsilon=0.0, backend="jnp")
+    device_params = _params(builder)
+    host_params = jax.tree.map(np.asarray, device_params)
+    nbytes = sum(leaf.nbytes for leaf in jax.tree.leaves(host_params))
+    copied = telemetry_on.counter("engine/param_bytes_to_device")
+    windows = np.zeros((2, WINDOW) + obs_shape, np.float32)
+    engine.select(host_params, ["a"], windows[:1], [0])          # prefill
+    assert copied.value == nbytes
+    engine.select(host_params, ["a"], windows[:1], [1])          # decode
+    assert copied.value == 2 * nbytes
+    engine.select(host_params, ["a", "b"], windows, [2, 0])      # both
+    assert copied.value == 4 * nbytes
+    engine.select(device_params, ["a", "b"], windows, [3, 1])
+    engine.select(device_params, ["a", "b"], windows, [4, 2])
+    assert copied.value == 4 * nbytes
+    snap = telemetry_on.snapshot()
+    stats = engine.stats()
+    assert snap["engine/prefill_ms"]["count"] == stats["prefill_batches"]
+    assert snap["engine/decode_ms"]["count"] == stats["decode_batches"]
+    assert stats["prefill_batches"] + stats["decode_batches"] == 6
+
+
+def _prefilled_learner(builder):
+    """The policy learner over a replay holding a few Catch episodes."""
+    table = builder.make_replay()
+    adder = builder.make_adder(table)
+    env = Catch(seed=1)
+    rng = np.random.RandomState(1)
+    while table.size() < builder.cfg.batch_size:
+        step = env.reset()
+        adder.add_first(step)
+        while not step.last():
+            action = np.int32(rng.randint(builder.num_actions))
+            step = env.step(action)
+            adder.add(action, step)
+    return builder.make_learner(builder.make_dataset(table),
+                                priority_update_cb=table.update_priorities)
+
+
+def test_learner_step_spans_split_the_step(tmp_path, telemetry_on):
+    """Input, update, priorities and metrics: one span each per step, in
+    that order, none overlapping another."""
+    import glob
+
+    from jax.profiler import ProfileData
+    learner = _prefilled_learner(_builder())
+    learner.step()                                # compile outside the trace
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for _ in range(3):
+            learner.step()
+    finally:
+        jax.profiler.stop_trace()
+    phases = ["input", "update", "priorities", "metrics"]
+    snap = telemetry_on.snapshot()
+    assert [snap[f"learner/{p}_ms"]["count"] for p in phases] == [4] * 4
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    spans = sorted((ev.start_ns, ev.start_ns + ev.duration_ns, ev.name,
+                    dict(ev.stats)["step"])
+                   for plane in ProfileData.from_file(path).planes
+                   for line in plane.lines for ev in line.events
+                   if ev.name.startswith("repro/learner/"))
+    assert [(name, step) for _, _, name, step in spans] == [
+        (f"repro/learner/{p}", step) for step in (1, 2, 3) for p in phases]
+    assert all(end <= nxt for (_, end, _, _), (nxt, _, _, _)
+               in zip(spans, spans[1:]))
+
+
 # ================================================================== serving
 class _FakeSource:
     """get_variables handing out a fresh params OBJECT each bump()."""
@@ -289,6 +370,34 @@ def test_transformer_inference_server_roundtrip():
         assert server.stats()["pool_held_slots"] == 0
     finally:
         server.stop()
+
+
+def test_server_batch_spans_nest_fetches_and_engine_calls(telemetry_on):
+    """One ``inference/batch`` span per server batch; the weight fetches
+    and the engine's calls inside it are counted where they happen."""
+    builder = _builder()
+    engine = builder.make_policy(evaluation=True).make_engine(num_slots=4)
+    params = jax.tree.map(np.asarray, _params(builder))
+    nbytes = sum(leaf.nbytes for leaf in jax.tree.leaves(params))
+    source = _FakeSource(params)
+    server = TransformerInferenceServer(engine, source, max_batch_size=2,
+                                        max_wait_ms=1.0, update_period=2)
+    obs_shape = builder.spec.observations.shape
+    try:
+        for t in range(5):
+            server.select_action(np.zeros((2, WINDOW) + obs_shape,
+                                          np.float32), np.full(2, t), "c")
+        stats = server.stats()
+    finally:
+        server.stop()
+    snap = telemetry_on.snapshot()
+    calls = stats["prefill_batches"] + stats["decode_batches"]
+    assert stats["batches"] == 5
+    assert snap["inference/batch_ms"]["count"] == 5
+    assert snap["variables/fetch_ms"]["count"] == 3   # batches 1, 2 and 4
+    assert snap["engine/param_bytes_to_device"]["value"] == calls * nbytes
+    assert "inference/batch_rows" not in snap
+    assert snap["inference/batch_occupancy"]["count"] == 5
 
 
 def test_server_rejects_new_requests_after_stop():

@@ -17,8 +17,8 @@ import pytest
 from repro.telemetry import (HUB_INTERFACE, NULL_METRIC, Counter, Gauge,
                              Histogram, MetricRegistry, MetricsHub,
                              MetricsPusher, WorkerTelemetry, format_report,
-                             merge_snapshots, quantile, strip_reservoirs,
-                             timer)
+                             merge_snapshots, quantile, span,
+                             strip_reservoirs)
 from repro.telemetry import registry as _registry
 
 
@@ -116,12 +116,76 @@ def test_registry_probe_prefix_collision_dedupes():
 
 
 def test_timer_observes_milliseconds():
-    h = Histogram("h")
-    with timer(h):
+    """A span times its block in milliseconds into ``<name>_ms``."""
+    reg = MetricRegistry(enabled=True)
+    with reg.span("h"):
         time.sleep(0.01)
-    snap = h.snapshot()
+    snap = reg.histogram("h_ms").snapshot()
     assert snap["count"] == 1
     assert snap["min"] >= 5.0   # ms, not seconds
+
+
+def test_enabled_span_records_exact_count_and_sum(monkeypatch):
+    reg = MetricRegistry(enabled=True)
+    ticks = iter([10.0, 10.25, 20.0, 20.5])
+    monkeypatch.setattr(_registry.time, "monotonic", lambda: next(ticks))
+    for step in range(2):
+        with reg.span("learner/update", step=step):
+            pass
+    snap = reg.histogram("learner/update_ms").snapshot()
+    assert snap["count"] == 2
+    assert snap["sum"] == 750.0
+    assert (snap["min"], snap["max"]) == (250.0, 500.0)
+
+
+def test_disabled_span_never_reads_the_clock(monkeypatch, telemetry_state):
+    def boom(*_):
+        raise AssertionError("a disabled span touched the clock or jax")
+
+    monkeypatch.setattr(_registry.time, "monotonic", boom)
+    monkeypatch.setattr(_registry, "_annotation", boom)
+    for reg in (MetricRegistry(enabled=False), _registry.get_registry()):
+        with reg.span("learner/input", step=3) as s:
+            pass
+        assert s is _registry._NULL_SPAN
+        assert reg.snapshot() == {}
+    with span("engine/decode", rows=2) as s:
+        pass
+    assert s is _registry._NULL_SPAN
+
+
+def test_span_looks_enablement_up_when_called(telemetry_state):
+    """Components built before ``configure`` still record once it runs."""
+    with span("variables/fetch"):
+        pass
+    _registry.configure(enabled=True)
+    with span("variables/fetch"):
+        pass
+    assert _registry.snapshot()["variables/fetch_ms"]["count"] == 1
+
+
+def test_span_appears_in_the_profiler_trace(tmp_path, telemetry_state):
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+    _registry.configure(enabled=True)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with span("learner/update", step=7):
+            jax.numpy.ones(4).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    events = [(ev.name, dict(ev.stats), ev.duration_ns)
+              for plane in ProfileData.from_file(path).planes
+              for line in plane.lines for ev in line.events
+              if ev.name.startswith("repro/")]
+    assert len(events) == 1
+    name, ids, duration_ns = events[0]
+    assert (name, ids) == ("repro/learner/update", {"step": 7})
+    assert 0 < duration_ns
+    assert _registry.snapshot()["learner/update_ms"]["count"] == 1
 
 
 # ----------------------------------------------------------- disabled mode
@@ -136,7 +200,7 @@ def test_disabled_registry_is_noop():
     c.inc()
     g.set(1.0)
     h.observe(2.0)
-    with timer(h):
+    with reg.span("h"):
         pass
     reg.probe("pool", lambda: {"x": 1})
     assert reg.snapshot() == {}
