@@ -1,6 +1,7 @@
 """Shared learner scaffolding for all agents."""
 from __future__ import annotations
 
+import threading
 import time
 from typing import Any, Callable, Dict, Iterator, NamedTuple, Optional, Sequence
 
@@ -23,15 +24,33 @@ class LearnerState(NamedTuple):
 class JaxLearner(Learner):
     """Generic learner: pulls batches from an iterator, applies a jitted SGD
     step, publishes weights, accumulates learner walltime (§4.2 — persists
-    through checkpoints)."""
+    through checkpoints).
+
+    The step donates the target network and the optimizer state: the new
+    ones are written over the old, so XLA has their memory for the step.
+    A state read through ``state`` is therefore valid only until the next
+    step; a thread other than the stepping one reads ``snapshot_state()``
+    (or ``get_variables``: params are never donated)."""
 
     def __init__(self, state: LearnerState, update_fn, iterator: Iterator,
                  priority_update_cb: Optional[Callable] = None):
-        self._state = state
-        # NOTE: no donation here — actors snapshot params from another
-        # thread (get_variables) and donation would delete buffers under
-        # them.  The large-model train steps (repro.launch.steps) donate.
-        self._update = jax.jit(update_fn)
+        self._state = _owned(state)
+        # ``_update`` is the step's program as seen from outside, ``(state,
+        # sample)``, donating nothing: a wrapper set in its place may keep
+        # or hand back the state it was given.  While it is the learner's
+        # own, ``step`` runs the donating program instead.
+        self._update = self._plain_update = jax.jit(update_fn)
+
+        def update(params, target_params, opt_state, steps, extra, sample):
+            return update_fn(LearnerState(params, target_params, opt_state,
+                                          steps, extra), sample)
+
+        # target and optimizer state donated; params are not, since
+        # get_variables reads them from other threads while the step runs
+        self._donating_update = jax.jit(update, donate_argnums=(1, 2))
+        # held from the update's dispatch to the new state's assignment, so
+        # snapshot_state never reads buffers a dispatch is donating
+        self._lock = threading.Lock()
         self._iterator = iterator
         self._priority_cb = priority_update_cb
         self._walltime = 0.0
@@ -44,7 +63,18 @@ class JaxLearner(Learner):
 
     @state.setter
     def state(self, s: LearnerState):
-        self._state = s
+        self._state = _owned(s)
+
+    @property
+    def steps(self) -> int:
+        """The state's step count: never donated, so any thread may read
+        it."""
+        return int(self._state.steps)
+
+    def snapshot_state(self) -> LearnerState:
+        """A host copy of the whole state, safe to take from any thread."""
+        with self._lock:
+            return jax.device_get(self._state)
 
     @property
     def learner_walltime(self) -> float:
@@ -57,10 +87,20 @@ class JaxLearner(Learner):
         self._num_steps += 1
         with _telemetry.span("learner/input", step=step):
             sample = next(self._iterator)
+        donating = self._update is self._plain_update
+        if donating and step == 0:
+            donated = (self._state.target_params, self._state.opt_state)
+            _telemetry.gauge("learner/donated_bytes").set(
+                sum(leaf.nbytes for leaf in jax.tree.leaves(donated)))
         with _telemetry.span("learner/update", step=step):
             t0 = time.time()
-            self._state, metrics, priorities = self._update(self._state,
-                                                            sample)
+            with self._lock:
+                if donating:
+                    self._state, metrics, priorities = \
+                        self._donating_update(*self._state, sample)
+                else:
+                    self._state, metrics, priorities = self._update(
+                        self._state, sample)
             jax.block_until_ready(priorities if priorities is not None
                                   else metrics)
             self._walltime += time.time() - t0
@@ -82,10 +122,13 @@ class JaxLearner(Learner):
                 for _ in (names or ("policy",))]
 
 
-def fresh_copy(tree):
-    """Deep-copy a pytree's buffers (so params/target_params can both be
-    donated without aliasing the same buffer twice)."""
-    return jax.tree.map(jnp.copy, tree)
+def _owned(state: LearnerState) -> LearnerState:
+    """``state`` with its donated parts in buffers of their own: a target
+    that is the params, or one state set into two learners, would otherwise
+    be donated under another reader."""
+    target_params, opt_state = jax.tree.map(
+        jnp.copy, (state.target_params, state.opt_state))
+    return state._replace(target_params=target_params, opt_state=opt_state)
 
 
 def importance_weights(probs: jax.Array, beta: float = 0.6) -> jax.Array:

@@ -15,7 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import optim
-from repro.agents.common import JaxLearner, LearnerState, fresh_copy
+from repro.agents.common import JaxLearner, LearnerState
 from repro.builders import AgentBuilder, BuilderOptions
 from repro.core.types import EnvironmentSpec
 from repro.networks.heads import l2_project
@@ -97,7 +97,7 @@ def make_learner(spec: EnvironmentSpec, cfg: ContinuousConfig,
     copt = optim.adam(cfg.critic_lr, clip=40.0)
     params = init(rng_key)
     opt_state = (popt.init(params), copt.init(params))
-    state = LearnerState(params, fresh_copy(params), opt_state,
+    state = LearnerState(params, params, opt_state,
                          jnp.zeros((), jnp.int32))
     atoms = jnp.linspace(cfg.vmin, cfg.vmax, cfg.num_atoms)
 

@@ -64,8 +64,7 @@ def make_learner(spec: EnvironmentSpec, cfg: DQNConfig, iterator: Iterator,
     init, apply, in_dim, num_actions = make_q_network(spec, cfg)
     opt = optim.adam(cfg.learning_rate, clip=40.0)
     params = init(rng_key)
-    from repro.agents.common import fresh_copy
-    state = LearnerState(params, fresh_copy(params), opt.init(params),
+    state = LearnerState(params, params, opt.init(params),
                          jnp.zeros((), jnp.int32))
 
     def loss_fn(params, target_params, sample: ReplaySample):

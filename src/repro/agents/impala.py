@@ -17,7 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import optim
-from repro.agents.common import JaxLearner, LearnerState, fresh_copy
+from repro.agents.common import JaxLearner, LearnerState
 from repro.builders import AgentBuilder, BuilderOptions
 from repro.core.actors import (STEP_MOD, BatchedFeedForwardActor,
                                _folded_policy)

@@ -14,8 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import optim
-from repro.agents.common import (JaxLearner, LearnerState, fresh_copy,
-                                 importance_weights)
+from repro.agents.common import JaxLearner, LearnerState, importance_weights
 from repro.builders import AgentBuilder, BuilderOptions
 from repro.core.types import EnvironmentSpec
 from repro.networks.lstm import LSTMNetwork, LSTMState
@@ -54,7 +53,7 @@ def make_learner(spec: EnvironmentSpec, cfg: R2D2Config, iterator: Iterator,
     net = make_network(spec, cfg)
     opt = optim.adam(cfg.learning_rate, clip=40.0)
     params = net.init(rng_key, net.in_dim)
-    state = LearnerState(params, fresh_copy(params), opt.init(params),
+    state = LearnerState(params, params, opt.init(params),
                          jnp.zeros((), jnp.int32))
     num_actions = spec.actions.num_values
 

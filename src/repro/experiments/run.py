@@ -303,7 +303,9 @@ def run_distributed_experiment(config: ExperimentConfig, num_actors: int,
         # Services (learner, replay, counter) are parent-resident under
         # both backends, so the parent can snapshot them directly; workers
         # hold no durable state (their experience is already in replay).
-        checkpointer.save(at_step, dist.learner.state,
+        # The learner steps in its own thread, so its state is read as a
+        # snapshot.
+        checkpointer.save(at_step, dist.learner.snapshot_state(),
                           replay=dist.table.state_dict(),
                           counts=counts,
                           meta={"mode": "distributed",
@@ -316,10 +318,10 @@ def run_distributed_experiment(config: ExperimentConfig, num_actors: int,
             if target is not None and counts.get("actor_steps", 0) >= target:
                 break
             if max_learner_steps is not None and \
-                    int(dist.learner.state.steps) >= max_learner_steps:
+                    dist.learner.steps >= max_learner_steps:
                 break
             if checkpointer and config.checkpoint_every:
-                learner_steps = int(dist.learner.state.steps)
+                learner_steps = dist.learner.steps
                 if learner_steps - (last_ckpt_step or 0) \
                         >= config.checkpoint_every:
                     _save_run(learner_steps, counts)
@@ -361,7 +363,7 @@ def run_distributed_experiment(config: ExperimentConfig, num_actors: int,
     total_steps = int(counts.get("actor_steps", 0))
     evals = ([(total_steps, _evaluate(config, builder, dist.learner))]
              if config.eval_episodes > 0 else [])
-    learner_steps = int(dist.learner.state.steps)
+    learner_steps = dist.learner.steps
     if checkpointer and learner_steps != last_ckpt_step:
         _save_run(learner_steps, counts)
     return ExperimentResult(

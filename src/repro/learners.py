@@ -723,6 +723,18 @@ class MultiLearner:
         for replica in self._replicas:
             replica.state = merged
 
+    @property
+    def steps(self) -> int:
+        """The merged view's step count, read from each replica's step
+        count alone: replica nodes step in their own threads, and each of
+        their steps donates the rest of the state it was given."""
+        return int(average_states([r.state.steps for r in self._replicas]))
+
+    def snapshot_state(self):
+        """The merged view from host copies of each replica's state, safe
+        to take while replica nodes step in their own threads."""
+        return average_states([r.snapshot_state() for r in self._replicas])
+
     # --------------------------------------------------------------- stats
     def stats(self) -> Dict[str, Any]:
         """Per-replica executed step counts + averaging rounds — what
@@ -821,6 +833,10 @@ class LearnerReplicaWorker:
                             self.param_server.push(self.replica_id,
                                                    self.learner.state,
                                                    self.steps_taken)
+                            # the service keeps what was pushed: step on
+                            # from a copy, so the next step's donation
+                            # leaves the pushed state whole
+                            self.learner.state = self.learner.state
                             merged = self.param_server.pull()
                         else:
                             merged = self.param_server.sync(
@@ -867,10 +883,11 @@ class LearnerReplicaWorker:
         self._down.clear()
 
     def state_dict(self) -> Dict[str, Any]:
-        """Snapshot for service failover: the learner pytree (replicas swap
-        it atomically, so a concurrent read is a consistent state) plus the
-        step count the restart accounting resumes from."""
-        return {"learner_state": self.learner.state,
+        """Snapshot for service failover: a host copy of the learner pytree
+        (the watchdog reads it from its own thread, while the run loop's
+        steps donate the live state) plus the step count the restart
+        accounting resumes from."""
+        return {"learner_state": self.learner.snapshot_state(),
                 "steps_taken": self.steps_taken}
 
     def load_state_dict(self, state: Dict[str, Any]):

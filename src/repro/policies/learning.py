@@ -19,8 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import optim
-from repro.agents.common import (JaxLearner, LearnerState, fresh_copy,
-                                 importance_weights)
+from repro.agents.common import JaxLearner, LearnerState, importance_weights
 from repro.core.types import EnvironmentSpec
 from repro.policies import network
 from repro.replay.dataset import ReplaySample
@@ -33,7 +32,7 @@ def make_learner(spec: EnvironmentSpec, cfg, iterator: Iterator, rng_key,
     arch = network.make_arch(cfg, num_actions)
     opt = optim.adam(cfg.learning_rate, clip=40.0)
     params = network.init(rng_key, arch, obs_dim, num_actions)
-    state = LearnerState(params, fresh_copy(params), opt.init(params),
+    state = LearnerState(params, params, opt.init(params),
                          jnp.zeros((), jnp.int32))
 
     def loss_fn(params, target_params, sample: ReplaySample):
