@@ -1,6 +1,7 @@
 """The comparison that decides ``correct``: what the timed path produced
-against the plain reference (``reference.py``), run after the program's
-state is freed.
+against the plain reference of the configuration's model family
+(``families/<family>.py``, passed in as ``family``), run after the
+program's state is freed.
 
 Learner: the first three steps the window's own learner took, on the
 batches it drew.  Compared are each step's loss, the first gradient as the
@@ -35,8 +36,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-import reference
 
 CONTROL_DTYPE = jnp.bfloat16
 BETA1 = 0.9
@@ -109,15 +108,15 @@ def _learner_numbers(losses, grad_norms, change_norms, priorities,
     return out
 
 
-def check_learner(arch, hp: dict, weights_seed: int, recorded: dict,
-                  controls: bool = False):
+def check_learner(family, arch, hp: dict, weights_seed: int,
+                  recorded: dict, controls: bool = False):
     """(compared numbers, extra readings) for the learner."""
     batches = [_batch(s) for s in recorded["batches"]]
     keys = [np.asarray(s.info.keys) for s in recorded["batches"]]
-    start = reference.make_params(weights_seed, arch)
+    start = family.make_params(weights_seed, arch)
     start_host = _leaves(jax.device_get(start))
-    losses, grad, after, prios = reference.train_three(start, arch, hp,
-                                                       batches)
+    losses, grad, after, prios = family.train_three(start, arch, hp,
+                                                    batches)
     grad_norms = _norms(grad)
     median = float(np.median(list(grad_norms.values())))
     change = {k: v - start_host[k] for k, v in _leaves(after).items()}
@@ -148,7 +147,7 @@ def check_learner(arch, hp: dict, weights_seed: int, recorded: dict,
         for label, dtype, data in (("control", CONTROL_DTYPE, batches),
                                    ("fault.half_batch", jnp.float32,
                                     [_half(b) for b in batches])):
-            c_losses, c_grad, c_after, c_prios = reference.train_three(
+            c_losses, c_grad, c_after, c_prios = family.train_three(
                 start, arch, hp, data, dtype)
             c_change = {k: _norm(v - start_host[k])
                         for k, v in _leaves(c_after).items()}
@@ -163,20 +162,20 @@ def check_learner(arch, hp: dict, weights_seed: int, recorded: dict,
     return numbers, extra
 
 
-def check_serve(arch, weights_seed: int, requests: dict,
+def check_serve(family, arch, weights_seed: int, requests: dict,
                 controls: bool = False):
     """(compared numbers, extra readings) for the served actions."""
     rows = np.flatnonzero(requests["ok"][requests["req_of_row"]]) \
         if requests["req_of_row"].size else np.zeros(0, np.int64)
     if rows.size == 0:
         return {"serve_gap": None}, {"serve_rows": 0}
-    params = reference.make_params(weights_seed, arch)
+    params = family.make_params(weights_seed, arch)
     windows = requests["window"][rows]
     lengths = np.minimum(requests["position"][rows] + 1, arch.window)
     served = requests["action"][rows]
     q_ref, q_ctl = [], []
-    last = jax.jit(reference.last_q, static_argnums=(1, 4))
-    every = jax.jit(reference.q_values, static_argnums=(1, 3))
+    last = jax.jit(family.last_q, static_argnums=(1, 4))
+    every = jax.jit(family.q_values, static_argnums=(1, 3))
     for lo in range(0, rows.size, SERVE_BLOCK):
         w = jnp.asarray(windows[lo:lo + SERVE_BLOCK])
         n = jnp.asarray(lengths[lo:lo + SERVE_BLOCK], jnp.int32)

@@ -32,7 +32,14 @@ import types
 HERE = os.path.dirname(os.path.abspath(__file__))
 
 
-def diagnose(keep: dict, hp: dict, config: dict) -> dict:
+def _program_arch(family, config: dict, num_actions: int):
+    """The program's own ``ArchConfig`` for the configuration."""
+    from repro.policies import network
+    return network.make_arch(
+        types.SimpleNamespace(**family.policy_fields(config)), num_actions)
+
+
+def diagnose(keep: dict, hp: dict, config: dict, family) -> dict:
     """Double-Q action flips between XLA's default precision (the program's
     forward) and ``highest`` (the reference's), step by step."""
     import jax
@@ -40,19 +47,14 @@ def diagnose(keep: dict, hp: dict, config: dict) -> dict:
     import numpy as np
 
     import checks
-    import reference
     from repro.policies import network
 
     arch = keep["arch"]
-    arch_prog = network.make_arch(types.SimpleNamespace(
-        num_layers=arch.num_layers, d_model=arch.d_model,
-        num_heads=arch.num_heads, num_kv_heads=arch.num_kv_heads,
-        d_ff=arch.d_ff, head_dim=arch.head_dim, window=arch.window),
-        arch.num_actions)
+    arch_prog = _program_arch(family, config, arch.num_actions)
     q_prog = jax.jit(lambda p, o: network.q_sequence(p, arch_prog, o))
-    q_ref = jax.jit(reference.q_values, static_argnums=(1, 3))
+    q_ref = jax.jit(family.q_values, static_argnums=(1, 3))
     batches = [checks._batch(s) for s in keep["recorded"]["batches"]]
-    start = reference.make_params(keep["seeds"]["weights"], arch)
+    start = family.make_params(keep["seeds"]["weights"], arch)
     params = start
     zeros = jax.tree.map(jnp.zeros_like, start)
     opt = (jnp.zeros((), jnp.float32), zeros, zeros)
@@ -80,19 +82,18 @@ def diagnose(keep: dict, hp: dict, config: dict) -> dict:
                         np.quantile(np.abs(qp - qr), 0.99)),
                     "flip_target_jumps": jump[flip].tolist()[:20],
                     "ref_loss_with_program_action": loss_prog_action})
-        params, opt, _, _, _ = reference._sgd_step(
+        params, opt, _, _, _ = family.sgd_step(
             params, start, opt, arch, hp_items, jnp.float32, batch)
     return out
 
 
-def witness(keep: dict) -> dict:
+def witness(keep: dict, config: dict, family) -> dict:
     """The program's own window oracle on the served rows the reference
     finds not best: how many it answers with the reference's best."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    import reference
     from repro.policies import network
 
     arch = keep["arch"]
@@ -100,18 +101,14 @@ def witness(keep: dict) -> dict:
     rows = np.flatnonzero(checked["ok"][checked["req_of_row"]])
     windows = checked["window"][rows]
     lengths = np.minimum(checked["position"][rows] + 1, arch.window)
-    params = reference.make_params(keep["seeds"]["weights"], arch)
-    q_ref = np.asarray(jax.jit(reference.last_q, static_argnums=(1, 4))(
+    params = family.make_params(keep["seeds"]["weights"], arch)
+    q_ref = np.asarray(jax.jit(family.last_q, static_argnums=(1, 4))(
         params, arch, jnp.asarray(windows), jnp.asarray(lengths, jnp.int32),
         jnp.float32))
     wrong = q_ref.argmax(-1) != checked["action"][rows]
     if not wrong.any():
         return {"rows": int(rows.size), "not_best": 0}
-    arch_prog = network.make_arch(types.SimpleNamespace(
-        num_layers=arch.num_layers, d_model=arch.d_model,
-        num_heads=arch.num_heads, num_kv_heads=arch.num_kv_heads,
-        d_ff=arch.d_ff, head_dim=arch.head_dim, window=arch.window),
-        arch.num_actions)
+    arch_prog = _program_arch(family, config, arch.num_actions)
     q_oracle = np.asarray(jax.jit(
         lambda p, o: network.q_sequence(p, arch_prog, o))(
             params, jnp.asarray(windows[wrong])))
@@ -174,6 +171,7 @@ def main():
     import harness
     bench = harness.load_benchmark(root)
     cell, config, traffic, limits = harness.resolve_cell(bench, args.workload)
+    family = harness.load_family(config["family"])
     for seed in [int(s) for s in args.seeds.split(",")]:
         keep = {}
         result = harness.run_cell(
@@ -187,9 +185,10 @@ def main():
                 "verdicts": verdicts(result["controls"], limits["limits"]),
                 "readings": result["controls"]}
         if args.diagnose:
-            line["diagnosis"] = diagnose(keep, traffic["learner"], config)
+            line["diagnosis"] = diagnose(keep, traffic["learner"], config,
+                                         family)
         if args.witness:
-            line["witness"] = witness(keep)
+            line["witness"] = witness(keep, config, family)
         print(json.dumps(line), flush=True)
         del keep
 

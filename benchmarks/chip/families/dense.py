@@ -1,7 +1,8 @@
-"""Plain reference of the transformer Q-policy and its sequence double-DQN
-learner, written from the published block description (Llama-style
-pre-norm: RMSNorm, rotary attention with grouped KV heads, SwiGLU MLP) and
-imports nothing of the program under test.
+"""The ``dense`` family: a plain reference of the transformer Q-policy and
+its sequence double-DQN learner, written from the published block
+description (Llama-style pre-norm: RMSNorm, rotary attention with grouped KV
+heads, SwiGLU MLP), and the operations its learner step needs.  It imports
+nothing of the program under test.
 
 The benchmark makes the weights here, from the seed, and hands them to the
 program; the reference regenerates them from the same seed.  Everything runs
@@ -14,6 +15,11 @@ The parameter tree has the layout the program consumes:
 ``obs_proj/{w,b}``, ``blocks/{ln1,ln2}/scale``, ``blocks/attn/{wq,wk,wv,wo}``,
 ``blocks/mlp/{w_gate,w_up,w_down}`` (each stacked over layers on axis 0),
 ``final_norm/scale`` and ``head``.
+
+Operation counts are of the algorithm, not of an implementation: attention
+counts each query against the keys it may see (the banded, causal window),
+pad rows count nothing, and nothing recomputed is counted twice.  A
+multiply and an add are two operations.
 """
 from __future__ import annotations
 
@@ -56,6 +62,19 @@ def arch_from_config(config: dict) -> Arch:
         window=int(config["max_position_embeddings"]),
         rope_theta=float(config["rope_theta"]),
         rms_norm_eps=float(config["rms_norm_eps"]),
+    )
+
+
+def policy_fields(config: dict) -> dict:
+    """The program's ``TransformerPolicyConfig`` fields for this model."""
+    return dict(
+        num_layers=config["num_hidden_layers"],
+        d_model=config["hidden_size"],
+        num_heads=config["num_attention_heads"],
+        num_kv_heads=config["num_key_value_heads"],
+        head_dim=config["head_dim"],
+        d_ff=config["intermediate_size"],
+        window=config["max_position_embeddings"],
     )
 
 
@@ -206,7 +225,7 @@ def _global_norm(tree):
 
 
 @functools.partial(jax.jit, static_argnums=(3, 4, 5))
-def _sgd_step(params, target_params, opt, arch, hp_items, dtype, batch):
+def sgd_step(params, target_params, opt, arch, hp_items, dtype, batch):
     """One Adam step; the weights are held in ``dtype``."""
     hp = dict(hp_items)
     (loss, priorities), grads = jax.value_and_grad(td_loss, has_aux=True)(
@@ -243,7 +262,7 @@ def train_three(params, arch: Arch, hp: dict, batches, dtype=jnp.float32):
     hp_items = tuple(sorted(hp.items()))
     losses, first_grad, priorities = [], None, []
     for batch in batches:
-        params, opt, loss, grads, prio = _sgd_step(params, target, opt, arch,
+        params, opt, loss, grads, prio = sgd_step(params, target, opt, arch,
                                                    hp_items, dtype, batch)
         losses.append(float(loss))
         priorities.append(np.asarray(prio))
@@ -251,3 +270,37 @@ def train_three(params, arch: Arch, hp: dict, batches, dtype=jnp.float32):
             first_grad = jax.device_get(grads)
         del grads
     return losses, first_grad, jax.device_get(params), priorities
+
+
+# ------------------------------------------------------------------ counts
+def matmul_weights(arch: Arch) -> int:
+    """Weights each token multiplies through: projection, blocks, Q head."""
+    d, h, kv = arch.d_model, arch.num_heads, arch.num_kv_heads
+    hd = arch.head_dim
+    per_layer = d * h * hd + 2 * d * kv * hd + h * hd * d + 3 * d * arch.d_ff
+    return arch.obs_dim * d + arch.num_layers * per_layer \
+        + d * arch.num_actions
+
+
+def attention_flops(arch: Arch, keys_seen: int) -> int:
+    """Scores and weighted values over ``keys_seen`` (query, key) pairs,
+    summed over layers and heads."""
+    return 4 * arch.num_layers * arch.num_heads * arch.head_dim * keys_seen
+
+
+def forward_flops(arch: Arch, tokens: int, keys_seen: int) -> int:
+    return 2 * matmul_weights(arch) * tokens + attention_flops(arch, keys_seen)
+
+
+def banded_keys(arch: Arch, length: int) -> int:
+    """(query, key) pairs of one causal row of ``length`` positions banded
+    at the policy window."""
+    return sum(min(t + 1, arch.window) for t in range(length))
+
+
+def learner_step_flops(arch: Arch, batch: int, seq_len: int) -> int:
+    """One SGD step: the online forward, the target forward and the backward
+    of the online network (twice its forward)."""
+    fwd = forward_flops(arch, batch * seq_len, batch * banded_keys(arch,
+                                                                  seq_len))
+    return 4 * fwd

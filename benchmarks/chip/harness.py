@@ -4,6 +4,8 @@ Everything that belongs to one configuration, traffic mix, per-layer metric
 or cell lives in a file of its own, found by the name ``BENCHMARK.json``
 gives it: ``configs/<config>.json`` (through the configuration's ``file``),
 ``traffic/<mix>.json``, ``metrics/<metric>.py`` and ``limits/<cell>.json``.
+A configuration names its model family, ``families/<family>.py``: the plain
+reference of that model, its weights and what its work counts.
 """
 from __future__ import annotations
 
@@ -43,6 +45,8 @@ def resolve_cell(bench: dict, name: str, root: str = ROOT):
     cell = cells[name]
     configs = {c["name"]: c for c in bench["configs"]}
     config = load_json(os.path.join(root, configs[cell["config"]]["file"]))
+    if "family" not in config:
+        raise KeyError(f"configuration {cell['config']!r} names no family")
     traffic = load_json(os.path.join(CHIP_DIR, "traffic",
                                      f"{cell['traffic']}.json"))
     limits = load_json(os.path.join(CHIP_DIR, "limits", f"{name}.json"))
@@ -55,13 +59,24 @@ def cell_metrics(bench: dict, cell_name: str, kind: str) -> list:
             if cell_name in m.get("workloads", [cell_name])]
 
 
-def load_reader(metric_name: str):
-    path = os.path.join(CHIP_DIR, "metrics", f"{metric_name}.py")
+def _load_module(kind: str, name: str):
+    path = os.path.join(CHIP_DIR, kind, f"{name}.py")
     spec = importlib.util.spec_from_file_location(
-        f"chip_metric_{metric_name.replace('.', '_')}", path)
+        f"chip_{kind}_{name.replace('.', '_')}", path)
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
-    return module.read
+    return module
+
+
+def load_reader(metric_name: str):
+    return _load_module("metrics", metric_name).read
+
+
+def load_family(family_name: str):
+    """The module ``families/<family_name>.py``: ``arch_from_config``,
+    ``policy_fields``, ``make_params``, ``q_values``, ``last_q``,
+    ``train_three``, ``sgd_step`` and ``learner_step_flops``."""
+    return _load_module("families", family_name)
 
 
 def derive_seeds(seed: int) -> dict:
@@ -72,17 +87,11 @@ def derive_seeds(seed: int) -> dict:
 
 
 def policy_kwargs(config: dict, traffic: dict) -> dict:
-    """``TransformerPolicyConfig`` fields: widths and depth from the
-    configuration, learning and serving knobs from the traffic mix."""
+    """``TransformerPolicyConfig`` fields: the model's from the configuration
+    through its family, learning and serving knobs from the traffic mix."""
     learner = traffic["learner"]
     return dict(
-        num_layers=config["num_hidden_layers"],
-        d_model=config["hidden_size"],
-        num_heads=config["num_attention_heads"],
-        num_kv_heads=config["num_key_value_heads"],
-        head_dim=config["head_dim"],
-        d_ff=config["intermediate_size"],
-        window=config["max_position_embeddings"],
+        load_family(config["family"]).policy_fields(config),
         epsilon=traffic["epsilon"],
         cache_slots=traffic["cache_slots"],
         backend="auto",
@@ -343,7 +352,7 @@ def run_cell(cell: dict, config: dict, traffic: dict, limits: dict,
     import checks
     import flops
     import program_internals
-    import reference
+    family = load_family(config["family"])
     peak = flops.peaks(device.device_kind) if require_tpu else None
     if require_tpu and not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         jax.config.update("jax_compilation_cache_dir",
@@ -357,7 +366,7 @@ def run_cell(cell: dict, config: dict, traffic: dict, limits: dict,
 
     seeds = derive_seeds(seed)
     check_prefill_fits(traffic)
-    arch = reference.arch_from_config(config)
+    arch = family.arch_from_config(config)
     obs_shape = tuple(config["observation_shape"])
     env_factory = CatchFactory(*obs_shape)
     log_dir = tempfile.mkdtemp(prefix="chip-bench-")
@@ -382,7 +391,7 @@ def run_cell(cell: dict, config: dict, traffic: dict, limits: dict,
         builder.record.release.set()
 
     def before_launch(learner, table, counter):
-        params = reference.make_params(seeds["weights"], arch)
+        params = family.make_params(seeds["weights"], arch)
         learner.state = learner.state._replace(
             params=params, target_params=jax.tree.map(jax.numpy.copy,
                                                       params))
@@ -422,11 +431,11 @@ def run_cell(cell: dict, config: dict, traffic: dict, limits: dict,
             jax.profiler.start_trace(profile_dir, profiler_options=options)
             window_span = jax.profiler.TraceAnnotation("bench/window")
             window_span.__enter__()
-        before = _snapshot(learner, server, builder)
+        before = _snapshot(learner, server, builder, trace)
         t0 = time.monotonic_ns()
         time.sleep(seconds)
         t1 = time.monotonic_ns()
-        after = _snapshot(learner, server, builder)
+        after = _snapshot(learner, server, builder, trace)
         if trace:
             window_span.__exit__(None, None, None)
             jax.profiler.stop_trace()
@@ -503,7 +512,8 @@ def run_cell(cell: dict, config: dict, traffic: dict, limits: dict,
                                       "unit": m["unit"]}
     else:
         ctx = {
-            "arch": arch, "traffic": traffic, "window_s": window_s,
+            "family": family, "arch": arch, "traffic": traffic,
+            "window_s": window_s,
             "before": before, "after": after,
             "steps_in_window": steps_in_window, "medians": medians,
             "trace": trace_summary, "peak": peak,
@@ -521,10 +531,10 @@ def run_cell(cell: dict, config: dict, traffic: dict, limits: dict,
 
     # ---- outputs against the reference, after the program is gone
     compared, extra = checks.check_learner(
-        arch, traffic["learner"], seeds["weights"], recorded,
+        family, arch, traffic["learner"], seeds["weights"], recorded,
         controls=controls)
-    numbers, serve_extra = checks.check_serve(arch, seeds["weights"],
-                                              checked, controls=controls)
+    numbers, serve_extra = checks.check_serve(
+        family, arch, seeds["weights"], checked, controls=controls)
     compared.update(numbers)
     extra.update(serve_extra)
     judged = {name: {"value": compared[name], "limit": limits["limits"][name]}
@@ -558,12 +568,19 @@ def run_cell(cell: dict, config: dict, traffic: dict, limits: dict,
     return result
 
 
-def _snapshot(learner, server, builder) -> dict:
+def _snapshot(learner, server, builder, telemetry: bool) -> dict:
+    """The counters the readers difference over the window; in a traced run
+    also the program's telemetry registry (exact counts and sums, no
+    reservoirs)."""
     stats = server.stats()
-    return {
+    out = {
         "learner_walltime": learner.learner_walltime,
         "learner_steps": len(builder.record.step_ends),
         "batches": stats["batches"],
         "prefill_rows": stats["prefill_rows"],
         "decode_rows": stats["decode_rows"],
     }
+    if telemetry:
+        from repro.telemetry import registry
+        out["telemetry"] = registry.strip_reservoirs(registry.snapshot())
+    return out

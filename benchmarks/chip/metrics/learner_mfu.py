@@ -1,7 +1,6 @@
 """The learner's share of the chip's bf16 peak: operations one SGD step
-needs (online and target forwards and the backward, from shapes) times the
-steps completed in the window, over the window and the peak."""
-import flops
+needs (counted from shapes by the configuration's family) times the steps
+completed in the window, over the window and the peak."""
 
 
 def read(ctx):
@@ -11,7 +10,7 @@ def read(ctx):
     if steps <= 0:
         return None
     learner = ctx["traffic"]["learner"]
-    per_step = flops.learner_step_flops(ctx["arch"], learner["batch_size"],
-                                        learner["sequence_length"])
+    per_step = ctx["family"].learner_step_flops(
+        ctx["arch"], learner["batch_size"], learner["sequence_length"])
     peak = ctx["peak"]["bf16_flops_per_s"]
     return 100.0 * per_step * steps / ctx["window_s"] / peak

@@ -4,7 +4,8 @@ underneath comes out not ``correct``, once for each fault the cells can
 have: a learner step that returns its state unchanged, half of the batch
 left out, the mean taken over the rest, and a served action altered where
 the engine produces it.  One chip has no exchange between chips to leave
-out.  The limits are the cells' own."""
+out.  The limits are the cells' own.  A traced run hands every per-layer
+reader that counts host work the program's telemetry."""
 import os
 import sys
 import time
@@ -20,7 +21,7 @@ import harness  # noqa: E402
 BENCH = harness.load_benchmark(ROOT)
 
 
-def _run(cell_name, seed=3):
+def _run(cell_name, seed=3, trace=False):
     cell, config, traffic, limits = harness.resolve_cell(BENCH, cell_name,
                                                          ROOT)
     config.update(harness.load_json(os.path.join(HERE, "testdata",
@@ -33,7 +34,7 @@ def _run(cell_name, seed=3):
                    envs_per_actor=min(traffic["envs_per_actor"], 2))
     traffic["max_batch_size"] = traffic["actors"] * traffic["envs_per_actor"]
     return harness.run_cell(
-        cell, config, traffic, limits, BENCH, seed, 1.5, False,
+        cell, config, traffic, limits, BENCH, seed, 1.5, trace,
         process_start_ns=time.monotonic_ns(), require_tpu=False,
         log=lambda message: None)
 
@@ -103,3 +104,21 @@ def test_fault_is_not_correct(cell_name, fault, monkeypatch):
     fault(monkeypatch)
     result = _run(cell_name)
     assert not result["correct"], result["compared"]
+
+
+PROGRAM_READERS = ("learner_input_ms", "learner_host_ms", "serve_batch_ms",
+                   "serve_param_bytes_per_batch", "learner_step_ms",
+                   "inference_rows_per_batch")
+
+
+@pytest.mark.parametrize("cell_name", CELLS)
+def test_traced_run_reads_the_program_telemetry(cell_name):
+    result = _run(cell_name, seed=5, trace=True)
+    assert result["correct"], result["compared"]
+    metrics = result["metrics"]
+    expected = {m["name"] for m in harness.cell_metrics(BENCH, cell_name,
+                                                        "per_layer")}
+    assert set(PROGRAM_READERS) & expected <= set(metrics), metrics
+    assert all(metrics[n]["value"] > 0 for n in PROGRAM_READERS
+               if n in expected)
+    assert "setup_s" not in metrics

@@ -1,6 +1,6 @@
-"""The benchmark's yardstick on the CPU: operation and byte counts checked by
-hand for a tiny configuration, the peak table, and the trace reduction on a
-small recorded trace."""
+"""The benchmark's yardstick on the CPU: the dense family's operation counts
+checked by hand for a tiny configuration, the peak table, and the trace
+reduction on a small recorded trace."""
 import json
 import os
 import sys
@@ -11,10 +11,11 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path[:0] = [HERE]
 
 import flops  # noqa: E402
+import harness  # noqa: E402
 import trace_reduce  # noqa: E402
-from reference import Arch  # noqa: E402
 
-TINY = Arch(obs_dim=50, num_actions=3, d_model=64, num_heads=4,
+DENSE = harness.load_family("dense")
+TINY = DENSE.Arch(obs_dim=50, num_actions=3, d_model=64, num_heads=4,
             num_kv_heads=2, head_dim=16, d_ff=128, num_layers=2, window=8,
             rope_theta=10000.0, rms_norm_eps=1e-6)
 
@@ -23,17 +24,17 @@ def test_matmul_weights_by_hand():
     # projection 50*64, per layer q 64*4*16 + k,v 2*64*2*16 + o 4*16*64
     # + SwiGLU 3*64*128, head 64*3
     per_layer = 4096 + 4096 + 4096 + 24576
-    assert flops.matmul_weights(TINY) == 3200 + 2 * per_layer + 192 == 77120
+    assert DENSE.matmul_weights(TINY) == 3200 + 2 * per_layer + 192 == 77120
 
 
 def test_banded_keys_by_hand():
     # positions 0..9 see 1..8 keys, then the window's 8
-    assert flops.banded_keys(TINY, 10) == 36 + 8 + 8
+    assert DENSE.banded_keys(TINY, 10) == 36 + 8 + 8
 
 
 def test_learner_step_flops_by_hand():
     fwd = 2 * 77120 * (2 * 10) + 4 * 2 * 4 * 16 * (2 * 52)
-    assert flops.learner_step_flops(TINY, batch=2, seq_len=10) == 4 * fwd
+    assert DENSE.learner_step_flops(TINY, batch=2, seq_len=10) == 4 * fwd
 
 
 def test_peaks_are_keyed_by_device_kind():
@@ -99,3 +100,18 @@ def test_trace_gap_labels_name_open_spans():
     # gap 20..50 (mid 35): both spans open; gap 60..100 (mid 80): sample
     assert gaps == {"engine.select+replay.sample": pytest.approx(30e-9),
                     "replay.sample": pytest.approx(40e-9)}
+
+
+def test_trace_hands_on_the_program_spans(recorded_trace):
+    events = {
+        "host": [["bench/window", 0, 100], ["bench/learner.step", 10, 90]],
+        "device": {"/device:TPU:0": [["m:a", 0, 20]]},
+        "program": [["repro/learner/input", 5, 9],
+                    ["repro/learner/update", 9, 58]],
+    }
+    out = trace_reduce.reduce(events)
+    assert out["program"] == events["program"]
+    assert out["window_ns"] == [0, 100]
+    # the program's spans label nothing: the gaps are the benchmark's
+    assert dict(out["idle_gaps"]) == {"learner.step": pytest.approx(80e-9)}
+    assert trace_reduce.reduce(recorded_trace)["program"] == []

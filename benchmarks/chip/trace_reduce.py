@@ -1,15 +1,16 @@
 """Reduction of a profiler trace to device busy time, kernel time and
 labelled idle gaps.
 
-``load`` reads the ``.xplane.pb`` the JAX profiler wrote and keeps two
+``load`` reads the ``.xplane.pb`` the JAX profiler wrote and keeps three
 kinds of event, each as ``[name, start_ns, end_ns]`` on the trace's clock:
 the device's operations (plane ``/device:TPU:<n>``, line ``XLA Ops``; the
 name is the HLO instruction's, such as ``decode_attention.7``; a Pallas
-kernel's instruction carries the kernel's name), and the benchmark's own
-host spans (names that start with ``bench/``, written with
-``jax.profiler.TraceAnnotation``).
+kernel's instruction carries the kernel's name), the benchmark's own host
+spans (``host``: names that start with ``bench/``, written with
+``jax.profiler.TraceAnnotation``), and the program's spans (``program``:
+names that start with ``repro/``, from ``repro.telemetry.span``).
 ``reduce`` works on that small form, so a recorded trace can be kept as
-JSON beside the tests.
+JSON beside the tests; it hands the program's spans on unchanged.
 """
 from __future__ import annotations
 
@@ -19,6 +20,7 @@ from collections import defaultdict
 from typing import Dict, List
 
 SPAN_PREFIX = "bench/"
+PROGRAM_PREFIX = "repro/"
 WINDOW_SPAN = "bench/window"
 
 
@@ -31,6 +33,7 @@ def load(profile_dir: str) -> dict:
     data = ProfileData.from_file(paths[-1])
     device: Dict[str, list] = defaultdict(list)
     host: list = []
+    program: list = []
     for plane in data.planes:
         on_device = plane.name.startswith("/device:TPU:")
         for line in plane.lines:
@@ -43,7 +46,9 @@ def load(profile_dir: str) -> dict:
                     device[plane.name].append([op_name(ev.name), start, end])
                 elif ev.name.startswith(SPAN_PREFIX):
                     host.append([ev.name, start, end])
-    return {"device": dict(device), "host": host}
+                elif ev.name.startswith(PROGRAM_PREFIX):
+                    program.append([ev.name, start, end])
+    return {"device": dict(device), "host": host, "program": program}
 
 
 def op_name(event_name: str) -> str:
@@ -95,7 +100,8 @@ def _label_gaps(gaps: List[list], spans: List[list]) -> Dict[str, float]:
 def reduce(events: dict, top: int = 10) -> dict:
     """Busy seconds (the union of the device's operations) averaged over the
     traced devices, seconds per operation name, the ``top`` operations by
-    device time, and the ``top`` idle-gap labels by seconds."""
+    device time, the ``top`` idle-gap labels by seconds, the window's bounds
+    on the trace's clock, and the program's spans as loaded."""
     lo, hi = window_bounds(events)
     window_s = (hi - lo) / 1e9
     spans = [e for e in events["host"] if e[0] != WINDOW_SPAN]
@@ -129,4 +135,6 @@ def reduce(events: dict, top: int = 10) -> dict:
                        sorted(op_s.items(), key=lambda kv: -kv[1])[:top]],
         "idle_gaps": [[n, s / count] for n, s in
                       sorted(gap_s.items(), key=lambda kv: -kv[1])[:top]],
+        "window_ns": [lo, hi],
+        "program": events.get("program", []),
     }
